@@ -3,18 +3,21 @@
 Three measurements:
 
   * ``async/overlap_*`` — wall-clock of the in-mesh step under
-    ``reduce_mode="serial" | "overlap" | "overlap_eager"`` on an 8-device
-    CPU mesh (spawned as a subprocess with a forced host device fleet,
-    the same trick tests/test_distributed.py uses).  On CPU the psum is a
-    memcpy, so overlap is reported for structure validation, not gated —
-    the scheduling win needs real interconnect latency to hide.
+    ``reduce_mode="serial" | "overlap" | "overlap_eager"`` on a data mesh
+    over every device of this process (``jax.devices()``: the chips of a
+    TPU host, or a forced CPU fleet under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  It runs in
+    the benchmark's own process, which already holds the devices.  On the
+    CPU the psum is a memcpy, so overlap is reported for structure
+    validation, not gated — the scheduling win needs real interconnect
+    latency to hide.
   * ``async/step_*`` — the gated number: per-step wall-clock of the
     barrier-free ``AsyncEngine`` (refresh r of K shards per step, stale
     fold for the rest) against the synchronous serial map-reduce step on
-    the same 8-device mesh and data.  The async step maps r/K of the
-    rows, so its speedup is honest work reduction (bounded-staleness
-    gradients are the price; docs/training.md quantifies it).  Gate:
-    >= 1.15x at n >= 512k with refresh=1.
+    the same mesh and data.  The async step maps r/K of the rows, so its
+    speedup is honest work reduction (bounded-staleness gradients are the
+    price; docs/training.md quantifies it).  Gate: >= 1.15x at n >= 512k
+    with refresh=1 on a mesh of several devices.
   * ``async/straggler_*`` — goodput under straggler injection in the
     established host-simulated idiom (gp_common/fig5/fig7): each shard is
     slowed by ``straggler_factor`` with probability ``rate`` per
@@ -27,21 +30,17 @@ Three measurements:
 """
 from __future__ import annotations
 
-import argparse
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
 
 # --------------------------------------------------------------------------
-# worker: runs inside the subprocess with an 8-device host fleet
+# the mesh comparison: every device this process holds
 # --------------------------------------------------------------------------
 
-def _worker(n: int, m: int, shards: int, chunk: int, iters: int,
-            refresh_sweep, staleness: int) -> None:
+def _mesh_steps(n: int, m: int, chunk: int, iters: int, refresh_sweep,
+                staleness: int):
     import jax
     import jax.numpy as jnp
 
@@ -51,8 +50,7 @@ def _worker(n: int, m: int, shards: int, chunk: int, iters: int,
 
     from .gp_common import default_hyp
 
-    assert len(jax.devices()) == shards, \
-        f"worker expected {shards} devices, got {len(jax.devices())}"
+    shards = len(jax.devices())
     q, d = 2, 1
     rng = np.random.default_rng(0)
     x = rng.standard_normal((n, q))
@@ -73,6 +71,7 @@ def _worker(n: int, m: int, shards: int, chunk: int, iters: int,
         return float(np.median(ts))
 
     mesh = make_compat_mesh((shards,), ("data",))
+    rows = []
     t_modes = {}
     for mode in ("serial", "overlap", "overlap_eager"):
         eng = DistributedGP(mesh, chunk_size=chunk, reduce_mode=mode)
@@ -81,15 +80,15 @@ def _worker(n: int, m: int, shards: int, chunk: int, iters: int,
         ones = jnp.ones((eng.n_shards,))
         t_modes[mode] = timed(vg, hyp, z, data["mu"], None, data["y"], w,
                               ones, nf)
-        print(f"ROW,async/overlap_mode={mode}_n={n},"
-              f"{t_modes[mode] * 1e6:.3f},"
-              f"vs_serial={t_modes['serial'] / t_modes[mode]:.2f}x")
+        rows.append((f"async/overlap_mode={mode}_n={n}_shards={shards}",
+                     t_modes[mode] * 1e6,
+                     f"vs_serial={t_modes['serial'] / t_modes[mode]:.2f}x"))
 
     # --- barrier-free async step vs the serial synchronous step ------------
     per = n // shards
     shard_data = [{"y": y[k * per:(k + 1) * per],
                    "mu": x[k * per:(k + 1) * per]} for k in range(shards)]
-    for r in refresh_sweep:
+    for r in [r for r in refresh_sweep if r <= shards]:
         eng_a = AsyncEngine(shard_data, d=d, staleness=staleness, refresh=r,
                             chunk_size=chunk)
         for _ in range(-(-shards // r)):   # populate every shard + warm jit
@@ -102,11 +101,14 @@ def _worker(n: int, m: int, shards: int, chunk: int, iters: int,
             ts.append(time.perf_counter() - t0)
         t_async = float(np.median(ts))
         speedup = t_modes["serial"] / t_async
-        print(f"ROW,async/step_refresh={r}_n={n}_shards={shards},"
-              f"{t_async * 1e6:.3f},speedup={speedup:.2f}x")
-        if r == min(refresh_sweep) and n >= 512_000:
+        rows.append((f"async/step_refresh={r}_n={n}_shards={shards}",
+                     t_async * 1e6, f"speedup={speedup:.2f}x"))
+        if r == min(refresh_sweep) and n >= 512_000 and shards > 1:
             assert speedup >= 1.15, \
                 f"async step speedup {speedup:.2f}x below the 1.15x gate"
+    for name, us, derived in rows:
+        print(f"  {name}: {us / 1e3:.1f} ms  {derived}")
+    return rows
 
 
 # --------------------------------------------------------------------------
@@ -220,36 +222,11 @@ def async_exec(n: int = 524_288, m: int = 32, shards: int = 8,
                straggler_rates=(0.0, 0.1, 0.3),
                straggler_factor: float = 8.0, straggler_iters: int = 10,
                n_strag: int = 20_000):
-    """Async/overlap execution benchmark.  The mesh comparison runs in a
-    subprocess (forced ``shards``-device host fleet, so the parent keeps
-    its single-device view); the straggler goodput curve is simulated
-    in-process.  Returns the usual (name, us_per_call, derived) rows."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={shards}")
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.abspath(src), env.get("PYTHONPATH", "")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.async_exec", "--worker",
-           f"--n={n}", f"--m={m}", f"--shards={shards}", f"--chunk={chunk}",
-           f"--iters={iters}", f"--staleness={staleness}",
-           "--refresh=" + ",".join(str(r) for r in refresh_sweep)]
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          cwd=os.path.dirname(os.path.dirname(
-                              os.path.abspath(__file__))))
-    rows = []
-    for line in proc.stdout.splitlines():
-        if line.startswith("ROW,"):
-            name, us, derived = line[4:].split(",", 2)
-            rows.append((name, float(us), derived))
-            print(f"  {name}: {float(us) / 1e3:.1f} ms  {derived}")
-        elif line.strip():
-            print(f"  [worker] {line}")
-    if proc.returncode != 0:
-        sys.stderr.write(proc.stderr)
-        raise RuntimeError(
-            f"async worker failed (exit {proc.returncode})")
-
+    """Async/overlap execution benchmark.  The mesh comparison runs on
+    every device of this process; the straggler goodput curve is
+    simulated in-process over ``shards`` host shards.  Returns the usual
+    (name, us_per_call, derived) rows."""
+    rows = _mesh_steps(n, m, chunk, iters, refresh_sweep, staleness)
     rows.extend(_straggler_goodput(n_strag, shards, straggler_rates,
                                    straggler_factor, straggler_iters, m))
     # fig. 7 shape: the async/sync goodput ratio must GROW with the
@@ -260,28 +237,3 @@ def async_exec(n: int = 524_288, m: int = 32, shards: int = 8,
         assert ratios[-1] >= ratios[0], \
             f"straggler ratio curve not increasing: {ratios}"
     return rows
-
-
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true")
-    ap.add_argument("--n", type=int, default=524_288)
-    ap.add_argument("--m", type=int, default=32)
-    ap.add_argument("--shards", type=int, default=8)
-    ap.add_argument("--chunk", type=int, default=4096)
-    ap.add_argument("--iters", type=int, default=3)
-    ap.add_argument("--staleness", type=int, default=16)
-    ap.add_argument("--refresh", type=str, default="1,2,4,8")
-    args = ap.parse_args()
-    refresh = tuple(int(r) for r in args.refresh.split(","))
-    if args.worker:
-        _worker(args.n, args.m, args.shards, args.chunk, args.iters,
-                refresh, args.staleness)
-    else:
-        async_exec(n=args.n, m=args.m, shards=args.shards, chunk=args.chunk,
-                   iters=args.iters, refresh_sweep=refresh,
-                   staleness=args.staleness)
-
-
-if __name__ == "__main__":
-    main()

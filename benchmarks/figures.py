@@ -20,8 +20,7 @@ from repro.distributed.fault import FailureSimulator, StepTimer
 from .gp_common import (default_hyp, make_shard_fn, mapreduce_iteration,
                         split_shards)
 # fig. 7's companion: straggler goodput + overlapped/async step timing
-# live in their own module (subprocess-based mesh sizing) — re-exported
-# here so figure-oriented callers find the whole fault/async family.
+# live in their own module — re-exported here so figure-oriented callers find the whole fault/async family.
 from .async_exec import async_exec  # noqa: F401,E402
 
 

@@ -2,6 +2,10 @@
 Prints ``name,us_per_call,derived`` CSV rows (plus human summaries).
 
   PYTHONPATH=src python -m benchmarks.run [--only fig2 fig7 ...] [--fast]
+
+Compiled programs are kept in JAX's persistent cache
+(``repro.launch.compile_cache``: ``$JAX_COMPILATION_CACHE_DIR`` or
+``.jax_cache/`` at the root of the checkout).
 """
 import argparse
 import sys
@@ -68,6 +72,8 @@ def main() -> None:
                     help="benchmarks to run (default: all; see list below)")
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only or list(ALL)
     rows = []
     for name in names:

@@ -63,22 +63,12 @@ from jax.sharding import PartitionSpec as P
 from .bound import DEFAULT_JITTER, collapsed_bound
 from .stats import Stats, fold_stats, partial_stats_chunked
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl  # type: ignore
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking disabled."""
-    try:
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:  # pragma: no cover - older kwarg name
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    """``jax.shard_map`` with replication checking off: the bodies psum by
+    hand and return replicated values the checker cannot prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 Array = jax.Array
 

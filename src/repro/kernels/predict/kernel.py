@@ -20,6 +20,8 @@ reduction visits are consecutive (the revolving-accumulator contract):
 
 Tiling contract (enforced/padded by ops.py):
   t % block_t == 0, m % block_m == 0, q and d padded to multiples of 8.
+  On the TPU block_m is a multiple of 128: the (block_m, block_m) ``g``
+  block is lane-aligned only then (docs/kernels.md).
   Padding is NEUTRAL: padded latent dims carry x=z=0, inv_ell2=1 (zero
   exponent contribution); padded inducing rows carry zero rows/cols of
   ``g`` and ``a_mean`` (their nonzero kernel columns multiply zeros);
@@ -30,6 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .._common import block_spec, dot_nn, se_tile
 
 
 def _predict_kernel(inv_ref, sf2_ref, za_ref, zb_ref, x_ref, g_ref, a_ref,
@@ -44,36 +48,23 @@ def _predict_kernel(inv_ref, sf2_ref, za_ref, zb_ref, x_ref, g_ref, a_ref,
         mean_ref[...] = jnp.zeros_like(mean_ref)
         quad_ref[...] = jnp.zeros_like(quad_ref)
 
-    inv = inv_ref[0, :]                                       # (q,)
-    sf2 = sf2_ref[0, 0]
+    inv = inv_ref[...]                                        # (1, q)
+    sf2 = sf2_ref[...]                                        # (1, 1)
     x = x_ref[...]                                            # (bt, q)
 
-    alpha = -0.5 * jnp.sum(x * x * inv[None, :], axis=1)      # (bt,)
-    m_mat = jnp.concatenate(
-        [x * inv[None, :],
-         jnp.broadcast_to(-0.5 * inv[None, :], x.shape)], axis=1)  # (bt, 2q)
+    ka = se_tile(x, inv, sf2, za_ref[...])                    # (bt, bm)
+    kb = se_tile(x, inv, sf2, zb_ref[...])
 
-    def k_tile(z):                                            # (bm, q) -> (bt, bm)
-        zc = jnp.concatenate([z, z * z], axis=1).T            # (2q, bm)
-        e = alpha[:, None] + jax.lax.dot(
-            m_mat, zc, precision=jax.lax.Precision.HIGHEST)
-        return sf2 * jnp.exp(e)
-
-    ka = k_tile(za_ref[...])
-    kb = k_tile(zb_ref[...])
-
-    tmp = jax.lax.dot(ka, g_ref[...],
-                      precision=jax.lax.Precision.HIGHEST)    # (bt, bm)
+    tmp = dot_nn(ka, g_ref[...])                              # (bt, bm)
     quad_ref[...] += jnp.sum(tmp * kb, axis=1, keepdims=True)
 
     @pl.when(first_b)
     def _acc_mean():
-        mean_ref[...] += jax.lax.dot(ka, a_ref[...],
-                                     precision=jax.lax.Precision.HIGHEST)
+        mean_ref[...] += dot_nn(ka, a_ref[...])
 
 
 def predict_pallas(inv_ell2, sf2, z, x, a_mean, g, *, block_t=128,
-                   block_m=64, interpret=False):
+                   block_m=128, interpret=False):
     """Fused (mean, quad) serving statistics. All inputs pre-padded (ops.py).
 
     inv_ell2: (1, q); sf2: (1, 1); z: (m, q); x: (t, q); a_mean: (m, d);
@@ -89,17 +80,17 @@ def predict_pallas(inv_ell2, sf2, z, x, a_mean, g, *, block_t=128,
         _predict_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q), lambda i, a, b: (0, 0)),            # inv_ell2
-            pl.BlockSpec((1, 1), lambda i, a, b: (0, 0)),            # sf2
-            pl.BlockSpec((block_m, q), lambda i, a, b: (a, 0)),      # z_a
-            pl.BlockSpec((block_m, q), lambda i, a, b: (b, 0)),      # z_b
-            pl.BlockSpec((block_t, q), lambda i, a, b: (i, 0)),      # x
-            pl.BlockSpec((block_m, block_m), lambda i, a, b: (a, b)),  # g
-            pl.BlockSpec((block_m, d), lambda i, a, b: (a, 0)),      # a_mean
+            block_spec((1, q), lambda i, a, b: (0, 0)),              # inv_ell2
+            block_spec((1, 1), lambda i, a, b: (0, 0)),              # sf2
+            block_spec((block_m, q), lambda i, a, b: (a, 0)),        # z_a
+            block_spec((block_m, q), lambda i, a, b: (b, 0)),        # z_b
+            block_spec((block_t, q), lambda i, a, b: (i, 0)),        # x
+            block_spec((block_m, block_m), lambda i, a, b: (a, b)),  # g
+            block_spec((block_m, d), lambda i, a, b: (a, 0)),        # a_mean
         ],
         out_specs=[
-            pl.BlockSpec((block_t, d), lambda i, a, b: (i, 0)),      # mean
-            pl.BlockSpec((block_t, 1), lambda i, a, b: (i, 0)),      # quad
+            block_spec((block_t, d), lambda i, a, b: (i, 0)),        # mean
+            block_spec((block_t, 1), lambda i, a, b: (i, 0)),        # quad
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, d), dt),

@@ -32,7 +32,7 @@ from . import kernel as _k
 @functools.partial(jax.jit, static_argnames=("block_t", "block_m", "interpret",
                                              "compute_dtype"))
 def predict_stats(hyp: dict, z, a_mean, g, x, block_t: int = 128,
-                  block_m: int = 64, interpret: bool | None = None,
+                  block_m: int = 128, interpret: bool | None = None,
                   compute_dtype=None):
     """Fused serving statistics via the Pallas kernel.
 
@@ -71,7 +71,7 @@ def predict_stats(hyp: dict, z, a_mean, g, x, block_t: int = 128,
     return mean[:t, :d], quad[:t, 0]
 
 
-def predict_fn_for_engine(block_t: int = 128, block_m: int = 64,
+def predict_fn_for_engine(block_t: int = 128, block_m: int = 128,
                           compute_dtype=None, kernel=None):
     """Adapter matching serve.engine's per-block fn: (state, x) -> (mean, var).
 

@@ -2,27 +2,32 @@
 
 The paper's cost model for the map step is O(n m^2 q) elementwise work per
 data shard (their §2.1/§3.2). A mechanical port would materialise the
-(n, m, m, q) broadcast — hostile to both VMEM and the MXU. We instead
-re-factor the exponent so the inner loops become matrix multiplies
-(TPU-native, MXU-aligned), which is the hardware adaptation of the paper's
-insight:
+(n, m, m, q) broadcast in HBM; this kernel streams it through VMEM one
+(block_n, block_m) slab at a time:
 
   psi2 exponent (per point i, inducing pair (a,b), latent dim q):
     E[i,ab] = static[ab] + lognorm_i - sum_q (mu_iq - zbar_abq)^2 / den_iq
     with den_iq = ell_q^2 + 2 s_iq, zbar = (z_a + z_b)/2.
-  Expanding the square decouples i from (ab):
-    E = alpha_i + M_i. @ Zb.ab,
-    M  = [2 mu/den, -1/den]               (n, 2q)
-    Zb = [zbar; zbar^2] (per ab column)   (2q, m^2)
-  so the kernel is two MXU matmuls + exp + one reduce matmul (w^T exp(E)),
-  tiled (block_n x block_m x block_m) so every operand lives in VMEM.
+  The kernel sums S_ab = sum_i w_i exp(E - static_ab) one inducing row a
+  at a time, straight from the definition — per latent dim q one
+  (block_n, block_m) pass of subtract, square, scale — so the live slab is
+  (block_n, block_m) and every store is a whole lane row.  ops.py hands it
+  zbar (q, m, m), the per-point columns mu and 1/den as (q, n, 1), and
+  lognorm (n, 1), all computed in the caller's f64 and rounded once, and
+  applies the i-free factor sf4 * exp(static_ab) in f64.  The exponential
+  is ``_common.exp_f32``: the USPS bound amplifies psi2 errors ~30x, and
+  the TPU's own f32 exp is off by up to 5.6e-6.  (Expanding the square
+  turns the q-loop into MXU matmuls, but its O(10) terms cancel to an O(1)
+  exponent.)
 
-psi1 uses the same trick one order lower.
+psi1 uses that expanded form one order lower:
+  E = alpha_i + (mu/den) z^T - (1/(2 den)) (z^2)^T, two MXU matmuls.
 
 Tiling contract (enforced/padded by ops.py):
-  n % block_n == 0, m % block_m == 0, q % q_pad == 0, all >= TPU lane rules.
-  q is padded NEUTRALLY: padded dims carry mu=s=z=0, ell2=1, which
-  contributes exactly 0 to every exponent term.
+  n % block_n == 0, m % block_m == 0, block_m % PSI2_ROWS == 0; on the TPU
+  block_m is a multiple of 128 (lane-aligned output rows).  psi2 needs no
+  latent padding (q is a leading, untiled axis).  psi1 pads q NEUTRALLY:
+  padded dims carry mu=s=z=0, ell2=1, which contribute exactly 0.
 """
 from __future__ import annotations
 
@@ -30,75 +35,61 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .._common import block_spec, dot_nt, exp_f32
+
+# Inducing rows (a) per psi2 grid step: one sublane tile.
+PSI2_ROWS = 8
+
 
 # ---------------------------------------------------------------------------
-# psi2: D = sum_i w_i <K_mi K_im>  — grid (a_tiles, b_tiles, n_tiles)
+# psi2: S = sum_i w_i exp(E_iab - static_ab) — grid (a_tiles, b_tiles, n_tiles)
 # ---------------------------------------------------------------------------
 
-def _psi2_kernel(ell2_ref, sf4_ref, za_ref, zb_ref, mu_ref, s_ref, w_ref,
-                 out_ref):
+def _psi2_kernel(zbar_ref, mu_ref, inv_den_ref, lognorm_ref, w_ref, out_ref):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ell2 = ell2_ref[0, :]                      # (q,)
-    mu = mu_ref[...]                           # (bn, q)
-    s = s_ref[...]                             # (bn, q)
-    w = w_ref[...]                             # (bn, 1)
-    za = za_ref[...]                           # (bm, q)
-    zb = zb_ref[...]                           # (bm, q)
-    bm = za.shape[0]
-
-    den = ell2[None, :] + 2.0 * s              # (bn, q)
-    inv_den = 1.0 / den
-    # lognorm_i = -0.5 sum_q log(den/ell2)
-    lognorm = -0.5 * jnp.sum(jnp.log(den) - jnp.log(ell2)[None, :], axis=1)
-    alpha = lognorm - jnp.sum(mu * mu * inv_den, axis=1)          # (bn,)
-    m_mat = jnp.concatenate([2.0 * mu * inv_den, -inv_den], axis=1)  # (bn, 2q)
-
-    zbar = 0.5 * (za[:, None, :] + zb[None, :, :])                # (bm, bm, q)
-    zb_mat = jnp.concatenate([zbar, zbar * zbar], axis=-1)        # (bm, bm, 2q)
-    zb_mat = zb_mat.reshape(bm * bm, -1).T                        # (2q, bm*bm)
-
-    dz = za[:, None, :] - zb[None, :, :]
-    static = -0.25 * jnp.sum(dz * dz / ell2[None, None, :], axis=-1)
-    static = static.reshape(1, bm * bm)                           # (1, bm*bm)
-
-    e = alpha[:, None] + jax.lax.dot(m_mat, zb_mat,
-                                     precision=jax.lax.Precision.HIGHEST)
-    p = jnp.exp(e + static)                                       # (bn, bm*bm)
-    acc = jax.lax.dot(w.T, p, precision=jax.lax.Precision.HIGHEST)
-    out_ref[...] += sf4_ref[0, 0] * acc.reshape(bm, bm)
+    q = mu_ref.shape[0]
+    lognorm = lognorm_ref[...]                                    # (bn, 1)
+    w = w_ref[...]                                                # (bn, 1)
+    for r in range(out_ref.shape[0]):
+        e = lognorm
+        for j in range(q):
+            t = mu_ref[j] - zbar_ref[j, r:r + 1, :]               # (bn, bm)
+            e = e - t * t * inv_den_ref[j]
+        out_ref[r:r + 1, :] += jnp.sum(w * exp_f32(e), axis=0,
+                                       keepdims=True)
 
 
-def psi2_pallas(ell2, sf4, z, mu, s, w, *, block_n=128, block_m=64,
+def psi2_pallas(zbar, mu, inv_den, lognorm, w, *, block_n=128, block_m=128,
                 interpret=False):
-    """w-weighted Psi2 (m, m). All inputs pre-padded (see ops.py).
+    """Unscaled w-weighted psi2 sums S (m, m). All inputs pre-padded and
+    pre-shaped (see ops.py, which applies ``sf4 * exp(static)``).
 
-    ell2: (1, q) f32; sf4: (1, 1) f32; z: (m, q); mu/s: (n, q); w: (n, 1).
+    zbar: (q, m, m); mu, inv_den: (q, n, 1); lognorm, w: (n, 1); all f32.
     """
-    n, q = mu.shape
-    m = z.shape[0]
-    assert n % block_n == 0 and m % block_m == 0
-    grid = (m // block_m, m // block_m, n // block_n)
+    q, n, _ = mu.shape
+    m = zbar.shape[1]
+    rows = PSI2_ROWS
+    assert n % block_n == 0 and m % block_m == 0 and m % rows == 0
+    grid = (m // rows, m // block_m, n // block_n)
     return pl.pallas_call(
         _psi2_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q), lambda a, b, k: (0, 0)),            # ell2
-            pl.BlockSpec((1, 1), lambda a, b, k: (0, 0)),            # sf4
-            pl.BlockSpec((block_m, q), lambda a, b, k: (a, 0)),      # z_a
-            pl.BlockSpec((block_m, q), lambda a, b, k: (b, 0)),      # z_b
-            pl.BlockSpec((block_n, q), lambda a, b, k: (k, 0)),      # mu
-            pl.BlockSpec((block_n, q), lambda a, b, k: (k, 0)),      # s
-            pl.BlockSpec((block_n, 1), lambda a, b, k: (k, 0)),      # w
+            block_spec((q, rows, block_m), lambda a, b, k: (0, a, b)),  # zbar
+            block_spec((q, block_n, 1), lambda a, b, k: (0, k, 0)),     # mu
+            block_spec((q, block_n, 1), lambda a, b, k: (0, k, 0)),     # 1/den
+            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # lognorm
+            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # w
         ],
-        out_specs=pl.BlockSpec((block_m, block_m), lambda a, b, k: (a, b)),
+        out_specs=block_spec((rows, block_m), lambda a, b, k: (a, b)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
         interpret=interpret,
-    )(ell2, sf4, z, z, mu, s, w)
+    )(zbar, mu, inv_den, lognorm, w)
 
 
 # ---------------------------------------------------------------------------
@@ -106,20 +97,19 @@ def psi2_pallas(ell2, sf4, z, mu, s, w, *, block_n=128, block_m=64,
 # ---------------------------------------------------------------------------
 
 def _psi1_kernel(ell2_ref, sf2_ref, z_ref, mu_ref, s_ref, out_ref):
-    ell2 = ell2_ref[0, :]
+    ell2 = ell2_ref[...]                        # (1, q)
     mu = mu_ref[...]                            # (bn, q)
     s = s_ref[...]                              # (bn, q)
     z = z_ref[...]                              # (bm, q)
 
-    den = ell2[None, :] + s
+    den = ell2 + s
     inv_den = 1.0 / den
-    lognorm = -0.5 * jnp.sum(jnp.log(den) - jnp.log(ell2)[None, :], axis=1)
-    alpha = lognorm - 0.5 * jnp.sum(mu * mu * inv_den, axis=1)      # (bn,)
-    m_mat = jnp.concatenate([mu * inv_den, -0.5 * inv_den], axis=1)  # (bn, 2q)
-    zb_mat = jnp.concatenate([z, z * z], axis=1).T                   # (2q, bm)
-    e = alpha[:, None] + jax.lax.dot(m_mat, zb_mat,
-                                     precision=jax.lax.Precision.HIGHEST)
-    out_ref[...] = sf2_ref[0, 0] * jnp.exp(e)
+    lognorm = -0.5 * jnp.sum(jnp.log(den) - jnp.log(ell2), axis=1,
+                             keepdims=True)
+    mdn = mu * inv_den
+    alpha = lognorm - 0.5 * jnp.sum(mu * mdn, axis=1, keepdims=True)
+    e = alpha + dot_nt(mdn, z) - dot_nt(0.5 * inv_den, z * z)       # (bn, bm)
+    out_ref[...] = sf2_ref[...] * jnp.exp(e)
 
 
 def psi1_pallas(ell2, sf2, z, mu, s, *, block_n=256, block_m=128,
@@ -133,13 +123,13 @@ def psi1_pallas(ell2, sf2, z, mu, s, *, block_n=256, block_m=128,
         _psi1_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_m, q), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n, q), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, q), lambda i, j: (i, 0)),
+            block_spec((1, q), lambda i, j: (0, 0)),
+            block_spec((1, 1), lambda i, j: (0, 0)),
+            block_spec((block_m, q), lambda i, j: (j, 0)),
+            block_spec((block_n, q), lambda i, j: (i, 0)),
+            block_spec((block_n, q), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, block_m), lambda i, j: (i, j)),
+        out_specs=block_spec((block_n, block_m), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
         interpret=interpret,
     )(ell2, sf2, z, mu, s)

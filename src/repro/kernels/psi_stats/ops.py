@@ -1,9 +1,10 @@
 """jit'd public wrappers around the psi-statistics Pallas kernels.
 
-Handles padding to tile boundaries (all pads are NEUTRAL — padded latent
-dims carry mu=s=z=0, ell2=1; padded data rows carry w=0; padded inducing
-rows are sliced off the output), backend selection (interpret=True off-TPU),
-and the hyper-parameter plumbing from the core library's log-space dict.
+Handles padding to tile boundaries (all pads are NEUTRAL — padded data
+rows carry w=0; padded inducing rows are sliced off the output; psi1's
+padded latent dims carry mu=s=z=0, ell2=1), the operand layouts the
+kernels want, backend selection (interpret=True off-TPU), and the
+hyper-parameter plumbing from the core library's log-space dict.
 
 ``psi2`` carries a ``custom_vjp`` (``pallas_call`` has no VJP on this JAX
 version): forward is the Pallas kernel, backward recomputes through the
@@ -30,22 +31,30 @@ def _psi2(block_n, block_m, interpret, hyp, z, mu, s, w):
 
 
 def _psi2_fwd_impl(block_n, block_m, interpret, hyp, z, mu, s, w):
+    # Everything outside the kernel runs in the caller's dtype (f64 here):
+    # the TPU's f32 log/exp would cost more accuracy than the kernel does.
     m = z.shape[0]
     f32 = jnp.float32
-    ell2 = jnp.exp(2.0 * hyp["log_ell"]).astype(f32)[None, :]       # (1, q)
-    sf4 = jnp.exp(2.0 * hyp["log_sf2"]).astype(f32)[None, None]     # (1, 1)
+    ell2 = jnp.exp(2.0 * hyp["log_ell"])                            # (q,)
+    den = ell2 + 2.0 * s                                             # (n, q)
+    lognorm = -0.5 * jnp.sum(jnp.log(den / ell2), axis=1, keepdims=True)
 
-    q_pad = 8
-    ell2 = _pad_to(ell2, q_pad, 1, value=1.0)
-    z_p = _pad_to(_pad_to(z.astype(f32), q_pad, 1), block_m, 0)
-    mu_p = _pad_to(_pad_to(mu.astype(f32), q_pad, 1), block_n, 0)
-    s_p = _pad_to(_pad_to(s.astype(f32), q_pad, 1), block_n, 0)
-    w_p = _pad_to(w.astype(f32)[:, None], block_n, 0)
+    def cols(a):                               # (n, q) -> (q, n_pad, 1) f32
+        return _pad_to(a.T[:, :, None].astype(f32), block_n, 1)
 
-    out = _k.psi2_pallas(ell2, sf4, z_p, mu_p, s_p, w_p,
+    # Padded rows are all zeros (exponent 0) and carry w = 0.
+    z_p = _pad_to(z, block_m, 0)
+    zbar = 0.5 * (z_p.T[:, :, None] + z_p.T[:, None, :])   # (q, m_pad, m_pad)
+    out = _k.psi2_pallas(zbar.astype(f32), cols(mu), cols(1.0 / den),
+                         _pad_to(lognorm.astype(f32), block_n, 0),
+                         _pad_to(w.astype(f32)[:, None], block_n, 0),
                          block_n=block_n, block_m=block_m,
                          interpret=interpret)
-    return out[:m, :m]
+    # The i-free factor of every term: sf4 * exp(-1/4 sum_q dz^2 / ell2).
+    dz = z[:, None, :] - z[None, :, :]
+    static = -0.25 * jnp.sum(dz * dz / ell2, axis=-1)               # (m, m)
+    sf4 = jnp.exp(2.0 * hyp["log_sf2"])
+    return sf4 * jnp.exp(static) * out[:m, :m].astype(static.dtype)
 
 
 def _psi2_vjp_fwd(block_n, block_m, interpret, hyp, z, mu, s, w):
@@ -67,7 +76,7 @@ _psi2.defvjp(_psi2_vjp_fwd, _psi2_vjp_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
-def psi2(hyp: dict, z, mu, s, w, block_n: int = 128, block_m: int = 64,
+def psi2(hyp: dict, z, mu, s, w, block_n: int = 128, block_m: int = 128,
          interpret: bool | None = None):
     """Weighted Psi2 = sum_i w_i <K_mi K_im> via the Pallas kernel. (m, m)."""
     interpret = (not _on_tpu()) if interpret is None else interpret
@@ -95,7 +104,7 @@ def psi1(hyp: dict, z, mu, s, block_n: int = 256, block_m: int = 128,
     return out[:n, :m]
 
 
-def psi2_fn_for_engine(block_n: int = 128, block_m: int = 64, kernel=None):
+def psi2_fn_for_engine(block_n: int = 128, block_m: int = 128, kernel=None):
     """Adapter matching core.stats.partial_stats(psi2_fn=...) signature.
 
     Dispatch shim for the compositional kernel layer: the fused Pallas

@@ -21,8 +21,9 @@ psi2: with ``inv_q = 1/ell_q^2``,
     M       = [x * inv, -inv/2]           (n, 2q)
     Zc      = [z; z^2] (per column a)     (2q, m)
 
-so each tile is one MXU matmul + exp, and the contractions are two more MXU
-matmuls ((bm, bn) @ (bn, bm) and (bm, bn) @ (bn, d)).
+so each tile is one MXU matmul + exp (``_common.se_tile``, which contracts
+lane dims so no in-kernel transpose or concat is needed), and the
+contractions are two more MXU matmuls over the row (sublane) axis.
 
 Grid (a_tiles, b_tiles, n_tiles), n innermost so every output block's
 reduction visits are consecutive (the revolving-accumulator contract):
@@ -32,6 +33,8 @@ reduction visits are consecutive (the revolving-accumulator contract):
 
 Tiling contract (enforced/padded by ops.py):
   n % block_n == 0, m % block_m == 0, q and d padded to multiples of 8.
+  On the TPU block_m is a multiple of 128: the (block_m, block_m) D block
+  is lane-aligned only then (docs/kernels.md).
   Padding is NEUTRAL: padded latent dims carry x=z=0, inv_ell2=1 (zero
   exponent contribution); padded data rows carry w=0 (zero weight kills all
   three statistics); padded y columns are 0; padded inducing rows are
@@ -42,6 +45,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .._common import block_spec, dot_tn, se_tile
 
 
 def _reg_stats_kernel(inv_ref, sf2_ref, za_ref, zb_ref, x_ref, y_ref, w_ref,
@@ -64,39 +69,27 @@ def _reg_stats_kernel(inv_ref, sf2_ref, za_ref, zb_ref, x_ref, y_ref, w_ref,
     def _init_d():
         d_ref[...] = jnp.zeros_like(d_ref)
 
-    inv = inv_ref[0, :]                                       # (q,)
-    sf2 = sf2_ref[0, 0]
+    inv = inv_ref[...]                                        # (1, q)
+    sf2 = sf2_ref[...]                                        # (1, 1)
     x = x_ref[...]                                            # (bn, q)
     w = w_ref[...]                                            # (bn, 1)
 
-    alpha = -0.5 * jnp.sum(x * x * inv[None, :], axis=1)      # (bn,)
-    m_mat = jnp.concatenate(
-        [x * inv[None, :],
-         jnp.broadcast_to(-0.5 * inv[None, :], x.shape)], axis=1)  # (bn, 2q)
+    ka = se_tile(x, inv, sf2, za_ref[...])                    # (bn, bm)
+    kb = se_tile(x, inv, sf2, zb_ref[...])
 
-    def k_tile(z):                                            # (bm, q) -> (bn, bm)
-        zc = jnp.concatenate([z, z * z], axis=1).T            # (2q, bm)
-        e = alpha[:, None] + jax.lax.dot(
-            m_mat, zc, precision=jax.lax.Precision.HIGHEST)
-        return sf2 * jnp.exp(e)
-
-    ka = k_tile(za_ref[...])
-    kb = k_tile(zb_ref[...])
-
-    d_ref[...] += jax.lax.dot((ka * w).T, kb,
-                              precision=jax.lax.Precision.HIGHEST)
+    d_ref[...] += dot_tn(ka * w, kb)
 
     @pl.when(first_b)
     def _acc_c():
-        c_ref[...] += jax.lax.dot(ka.T, w * y_ref[...],
-                                  precision=jax.lax.Precision.HIGHEST)
+        c_ref[...] += dot_tn(ka, w * y_ref[...])
 
     @pl.when(first_ab)
     def _acc_b():
-        b_ref[0, 0] += sf2 * jnp.sum(w)
+        # A (1, 1) vector store: Mosaic refuses scalar stores to VMEM.
+        b_ref[...] += sf2 * jnp.sum(w, axis=0, keepdims=True)
 
 
-def reg_stats_pallas(inv_ell2, sf2, z, x, y, w, *, block_n=128, block_m=64,
+def reg_stats_pallas(inv_ell2, sf2, z, x, y, w, *, block_n=128, block_m=128,
                      interpret=False):
     """Fused (b, C, D) regression statistics. All inputs pre-padded (ops.py).
 
@@ -113,18 +106,18 @@ def reg_stats_pallas(inv_ell2, sf2, z, x, y, w, *, block_n=128, block_m=64,
         _reg_stats_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q), lambda a, b, k: (0, 0)),            # inv_ell2
-            pl.BlockSpec((1, 1), lambda a, b, k: (0, 0)),            # sf2
-            pl.BlockSpec((block_m, q), lambda a, b, k: (a, 0)),      # z_a
-            pl.BlockSpec((block_m, q), lambda a, b, k: (b, 0)),      # z_b
-            pl.BlockSpec((block_n, q), lambda a, b, k: (k, 0)),      # x
-            pl.BlockSpec((block_n, d), lambda a, b, k: (k, 0)),      # y
-            pl.BlockSpec((block_n, 1), lambda a, b, k: (k, 0)),      # w
+            block_spec((1, q), lambda a, b, k: (0, 0)),              # inv_ell2
+            block_spec((1, 1), lambda a, b, k: (0, 0)),              # sf2
+            block_spec((block_m, q), lambda a, b, k: (a, 0)),        # z_a
+            block_spec((block_m, q), lambda a, b, k: (b, 0)),        # z_b
+            block_spec((block_n, q), lambda a, b, k: (k, 0)),        # x
+            block_spec((block_n, d), lambda a, b, k: (k, 0)),        # y
+            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # w
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda a, b, k: (0, 0)),            # b
-            pl.BlockSpec((block_m, d), lambda a, b, k: (a, 0)),      # C
-            pl.BlockSpec((block_m, block_m), lambda a, b, k: (a, b)),  # D
+            block_spec((1, 1), lambda a, b, k: (0, 0)),              # b
+            block_spec((block_m, d), lambda a, b, k: (a, 0)),        # C
+            block_spec((block_m, block_m), lambda a, b, k: (a, b)),  # D
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), dt),

@@ -71,7 +71,7 @@ _reg_stats.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
-def reg_stats(hyp: dict, z, x, y, w, block_n: int = 128, block_m: int = 64,
+def reg_stats(hyp: dict, z, x, y, w, block_n: int = 128, block_m: int = 128,
               interpret: bool | None = None):
     """Fused regression map statistics via the Pallas kernel.
 
@@ -82,7 +82,7 @@ def reg_stats(hyp: dict, z, x, y, w, block_n: int = 128, block_m: int = 64,
     return _reg_stats(block_n, block_m, interpret, hyp, z, x, y, w)
 
 
-def reg_stats_fn_for_engine(block_n: int = 128, block_m: int = 64,
+def reg_stats_fn_for_engine(block_n: int = 128, block_m: int = 128,
                             kernel=None):
     """Adapter matching core.stats.partial_stats(reg_stats_fn=...) signature.
 

@@ -4,51 +4,23 @@ A FUNCTION, not a module-level constant — importing this module never
 touches jax device state. Single pod: (data=16, model=16) = 256 chips;
 multi-pod: (pod=2, data=16, model=16) = 512 chips.
 
-``make_compat_mesh`` is the version-compat constructor every caller must
-route through: ``jax.sharding.AxisType`` / the ``axis_types=`` kwarg only
-exist on newer JAX, and very old JAX lacks ``jax.make_mesh`` entirely.
+``make_compat_mesh`` is the one mesh constructor every caller routes
+through, so all meshes share one axis-type policy.
 """
 from __future__ import annotations
 
-import inspect
-import math
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_compat_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """Build a device mesh across JAX versions.
-
-    Prefers ``jax.make_mesh(..., axis_types=(AxisType.Auto, ...))`` (newer
-    JAX), falls back to plain ``jax.make_mesh`` when ``AxisType`` or the
-    kwarg is missing, and finally to a hand-rolled ``jax.sharding.Mesh``
-    over ``jax.devices()`` when ``jax.make_mesh`` itself is absent.
-    """
-    shape = tuple(shape)
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices, every axis
+    ``AxisType.Auto`` (shardings are propagated, not explicit)."""
     axis_names = tuple(axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if hasattr(jax, "make_mesh"):
-        # Scope the fallback to the known drift (the kwarg's existence)
-        # rather than a bare except TypeError, which would also swallow
-        # genuine caller errors and re-raise something unrelated.
-        if axis_type is not None and _accepts_axis_types(jax.make_mesh):
-            return jax.make_mesh(
-                shape, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        return jax.make_mesh(shape, axis_names)
-    import numpy as np  # pragma: no cover - ancient-JAX fallback
-
-    n = math.prod(shape)
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devices, axis_names)
-
-
-def _accepts_axis_types(make_mesh) -> bool:
-    try:
-        return "axis_types" in inspect.signature(make_mesh).parameters
-    except (TypeError, ValueError):  # signature not introspectable
-        return False
+    return jax.make_mesh(tuple(shape), axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

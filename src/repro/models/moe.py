@@ -198,22 +198,9 @@ def moe_sharded(cfg, p, x: Array):
         aux = {k: lax.pmean(v, "model") for k, v in aux.items()}
         return y, aux
 
-    fn = shlib_shard_map(block, mesh,
-                         in_specs=(x_spec, w_specs),
-                         out_specs=(x_spec, P()))
+    fn = jax.shard_map(block, mesh=mesh, in_specs=(x_spec, w_specs),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(x, {k: p[k] for k in w_specs})
-
-
-def shlib_shard_map(f, mesh, in_specs, out_specs):
-    # jax.shard_map only exists (with check_vma) on newer JAX; older
-    # versions raise AttributeError on access or TypeError on the kwarg.
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def moe_forward(cfg, p, x: Array):

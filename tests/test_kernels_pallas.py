@@ -33,6 +33,20 @@ def test_psi2_kernel_shapes(rng, n, m, q):
                                rtol=2e-4, atol=2e-5)
 
 
+def test_exp_f32_matches_f64_exp():
+    """The kernels' VPU exponential: f32 round-off over the whole f32
+    range, exactly 0 below it."""
+    from repro.kernels._common import exp_f32
+
+    x = np.concatenate([np.linspace(-87.0, 20.0, 200_001),
+                        [-1e-30, 0.0, 1e-30]]).astype(np.float32)
+    got = np.asarray(exp_f32(jnp.asarray(x)), np.float64)
+    want = np.exp(x.astype(np.float64))
+    assert np.max(np.abs(got - want) / want) < 2e-7
+    assert np.all(np.asarray(exp_f32(jnp.asarray([-88.0, -1e4],
+                                                 jnp.float32))) == 0.0)
+
+
 @pytest.mark.parametrize("n,m,q", [(64, 16, 2), (100, 37, 3), (130, 129, 5)])
 def test_psi1_kernel_shapes(rng, n, m, q):
     hyp = _hyp(rng, q)
