@@ -17,8 +17,11 @@ One chip:
   serving  ``PredictEngine(kernel_backend="pallas")`` on 4096-row query
            batches against the XLA engine, then concurrent ``Frontend``
            requests.
-  gplvm    ``gplvm-usps`` (n=4649, d=256, q=10, m=150): value-and-grad
-           steps with the fused psi2, fused against XLA.
+  gplvm    ``gplvm-usps`` (n=4649, d=256, q=10, m=150) with the fused
+           psi2: the bound fused against XLA; at ``default_hyp``, where a
+           float32 D makes the bound indefinite, the fused bound raises
+           and the XLA bound is finite; value and gradients fused against
+           XLA on a 512-row slice; value-and-grad steps at full size.
   reference  the fused bound on a 20,000-row slice against
            ``ref_naive.titsias_bound_direct`` in float64 on the CPU.
 Four chips (``--chips 4``): the flight exact bound and gradients, and one
@@ -55,11 +58,22 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # to against f64 (benchmarks/streaming.py); a bug (a dropped block, a
 # wrong padding weight, a misplaced tile) moves the bound by far more.
 BOUND_RTOL = 1e-4
-# Same f32-statistics argument for the USPS GPLVM's psi statistics: the
-# fused psi2 expands the exponent (cancellation ~1e-6 relative per term).
-# Rounding the f64 statistics of this phase's start point to f32 moves its
-# bound by 1.2e-6 relative (CPU, float64 reference).
+# Same f32-statistics argument for the USPS GPLVM: the fused psi2 sums D
+# in f32 per block, folded in f64.  Rounding the f64 statistics of this
+# phase's start point to f32 moves its bound by 1.2e-6 relative (CPU,
+# float64 reference).
 GPLVM_RTOL = 1e-4
+# The fused path's backward is the float64 XLA recompute
+# (kernels/psi_stats/ops.py), so the gradients' only f32 input is the
+# forward D, through the bound's cotangent.  Where Kmm is ill-conditioned
+# that input alone moves the gradients far more than the bound: on the
+# USPS 512-row slice, rounding the exact D to float32 moves the worst
+# leaf (z) by 6.0e-3 of its largest entry and the bound by 1.3e-6 (CPU,
+# float64).  So the fused gradients are held to a multiple of that floor,
+# measured in the same run.  On the chip the fused psi2 measured 4.9x that
+# floor (PERF.md); a wrong cotangent, weight or transpose moves them by
+# O(1).
+GPLVM_GRAD_FACTOR = 10.0
 # Served mean / variance: the predict kernel runs in f32 against the f64
 # XLA engine.  The variance is k** - quad with quad close to k** when the
 # posterior is tight, so its error is measured against the prior variance
@@ -68,14 +82,20 @@ GPLVM_RTOL = 1e-4
 # CPU, 200k-row flight state); tiles rounded to bf16 would give ~1e-3.
 SERVE_MEAN_TOL = 1e-4
 SERVE_VAR_TOL = 1e-4
-# Four chips against device 0: the same f32 block statistics, folded and
-# summed in the TPU's emulated f64 in another order (per shard, then one
-# all-reduce).  On the CPU (IEEE f64) the two agree to 1e-16 on the bound
-# and 2e-15 on the gradients.  The first four-chip run measured 8.1e-8 on
-# the bound and 9.8e-6 on the gradients, and two one-device programs over
-# the same blocks differed by 1.9e-9: emulated f64 sums are good to ~1e-8
-# there (PERF.md).  The limits are set from that run with ~10x room; a
-# lost or doubled shard moves the bound by ~25%, a bf16 reduce by ~1e-3.
+# Four chips against device 0.  Each shard holds whole 2048-row blocks, so
+# both sides sum the same f32 block statistics, and on the CPU (IEEE f64)
+# they agree to 1e-16 on the bound and 2e-15 on the gradients.  The first
+# four-chip run measured 8.1e-8 on the bound and 9.8e-6 on the gradients:
+# the fused regression kernel handed back f32 statistics, so the blocks
+# were folded in f32, and 245 blocks per shard summed differently from 977
+# on one device (1e-6 of D; PERF.md).  The kernel's outputs are now cast
+# to float64 before the fold, and a four-chip probe then found the reduced
+# C and D of 4 chips and device 0 equal bit for bit.  What is left is the
+# emulated-f64 global step compiled into two programs (one program on the
+# chip against the CPU, same Stats: 1.8e-8 on the bound, 3.1e-8 on the
+# gradients).  The limits were set from the first run with ~10x room and
+# are kept until this phase is run again on four chips: a lost or
+# doubled shard moves the bound by ~25%, a reduce in bf16 by ~1e-3.
 MESH_BOUND_RTOL = 1e-6
 MESH_GRAD_RTOL = 1e-4
 
@@ -300,48 +320,95 @@ def phase_serving(ck: Checks, state, rows, batches, requests, request_rows):
               max(e[1] for e in errs), SERVE_VAR_TOL)
 
 
-def phase_gplvm(ck: Checks, mesh, n, d, q, m, chunk, steps):
+def phase_gplvm(ck: Checks, mesh, n, d, q, m, chunk, steps, slice_rows):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import DistributedGP
+    from repro.core.covariance import as_kernel
     from repro.core.init_utils import default_hyp, kmeans, pca
     from repro.data.synthetic import usps_like
 
     rng = np.random.default_rng(0)
     y, _ = usps_like(rng, n=n)
     mu0 = pca(y, q)
-    hyp = {k: jnp.asarray(v) for k, v in default_hyp(y, q).items()}
-    # Unit lengthscales on the unit-variance PCA latents.  At default_hyp's
-    # sqrt(q) the 150 inducing points are so correlated that rounding the
-    # exact f64 Stats to f32 already makes the bound NaN (B = I +
-    # beta L^-1 D L^-T loses definiteness), so no f32 map can run there;
-    # see PERF.md, Open questions.
-    hyp["log_ell"] = jnp.zeros(q)
+    hyp0 = {k: jnp.asarray(v) for k, v in default_hyp(y, q).items()}
     z = jnp.asarray(kmeans(mu0, m, iters=5))
     s0 = np.full_like(mu0, 0.5)
 
-    def engine(backend):
+    def engine(backend, rows=n, chunk=chunk, psi2_fn=None):
         eng = DistributedGP(mesh, latent=True, chunk_size=chunk,
-                            kernel_backend=backend)
-        data, w = eng.put_data(y=y, mu=mu0, s=s0)
+                            kernel_backend=backend, psi2_fn=psi2_fn)
+        data, w = eng.put_data(y=y[:rows], mu=mu0[:rows], s=s0[:rows])
         return eng, data, (w, jnp.ones((eng.n_shards,)),
-                           jnp.asarray(float(n)))
+                           jnp.asarray(float(rows)))
 
-    # The bound at the start point, fused and XLA.  Forward only: the XLA
-    # psi2's backward keeps (chunk, m, m, q) residuals per block, more
-    # than the chip's 16 GB at this width.
-    bounds = {}
+    progs = {}
     for backend in ("pallas", "xla"):
         eng, data, fixed = engine(backend)
-        bound = jax.jit(eng.bound_fn(d))
-        bounds[backend], dt = timed(bound, hyp, z, data["y"], data["mu"],
-                                    data["s"], *fixed)
+        progs[backend] = (jax.jit(eng.bound_fn(d)), data, fixed)
+
+    def bound_at(backend, hyp):
+        fn, data, fixed = progs[backend]
+        return fn(hyp, z, data["y"], data["mu"], data["s"], *fixed)
+
+    # Unit lengthscales, where a float32 D resolves Kmm: the bound at the
+    # start, fused and XLA.  Forward only at full size: the XLA psi2's
+    # backward keeps (chunk, m, m, q) residuals per block, more than the
+    # chip's 16 GB at this width.
+    hyp = dict(hyp0, log_ell=jnp.zeros(q))
+    bounds = {}
+    for backend in progs:
+        bounds[backend], dt = timed(bound_at, backend, hyp)
         log(f"gplvm/bound ({backend}) at the start: "
             f"{float(bounds[backend])!r}; first call {dt:.3f} s")
     ck.within("gplvm/bound_fused_vs_xla_rel",
               rel_err(bounds["pallas"], bounds["xla"]), GPLVM_RTOL)
+
+    # default_hyp starts at lengthscale sqrt(q) on the unit-variance PCA
+    # latents, where Kmm is so ill-conditioned that a float32 D makes
+    # Bmat indefinite: there the fused bound must raise, not return NaN,
+    # and the float64 XLA bound is finite.
+    try:
+        fused = float(bound_at("pallas", hyp0))
+    except Exception as e:                   # noqa: BLE001 — the guard
+        fused = e
+        _drain_effects()
+    ck.true("gplvm/default_start_fused_raises",
+            "not positive definite" in str(fused),
+            f"fused: {type(fused).__name__}")
+    xla = float(bound_at("xla", hyp0))
+    ck.true("gplvm/default_start_xla_finite", bool(np.isfinite(xla)),
+            f"xla bound={xla!r}")
+
+    # Value and gradients, fused against XLA, on the first rows, where the
+    # XLA backward fits; and the XLA gradients with its exact D rounded to
+    # float32 (forward only), the error any float32 D carries here.
+    def rounded_psi2(hyp, z, mu, s, w):
+        exact = as_kernel(None).psi2(hyp, z, mu, s, w)
+        return exact + jax.lax.stop_gradient(
+            exact.astype(jnp.float32).astype(exact.dtype) - exact)
+
+    grads = {}
+    for name, backend, psi2_fn in (("pallas", "pallas", None),
+                                   ("xla", "xla", None),
+                                   ("xla_f32_d", "xla", rounded_psi2)):
+        eng, data, fixed = engine(backend, rows=slice_rows, chunk=256,
+                                  psi2_fn=psi2_fn)
+        vg = eng.make_value_and_grad(d, argnums=(0, 1, 2))
+        grads[name], dt = timed(vg, hyp, z, data["mu"], data["s"],
+                                data["y"], *fixed)
+        log(f"gplvm/{slice_rows}-row slice ({name}): bound "
+            f"{-float(grads[name][0])!r}; first call {dt:.3f} s")
+    (v1, g1), (vx, gx) = grads["pallas"], grads["xla"]
+    ck.within("gplvm/slice_bound_fused_vs_xla_rel", rel_err(v1, vx),
+              GPLVM_RTOL)
+    floor = _tree_rel(grads["xla_f32_d"][1], gx)
+    log(f"gplvm/slice gradients: fused vs XLA {_tree_rel(g1, gx)!r}, "
+        f"XLA with float32 D vs XLA {floor!r}")
+    ck.within("gplvm/slice_grad_fused_vs_xla_over_f32_floor",
+              _tree_rel(g1, gx) / floor, GPLVM_GRAD_FACTOR)
 
     eng, data, fixed = engine("pallas")
     vg = eng.make_value_and_grad(d, argnums=(0, 1, 2))
@@ -360,6 +427,16 @@ def phase_gplvm(ck: Checks, mesh, n, d, q, m, chunk, steps):
         params, opt = adam(params, g, opt, i + 1, lr=1e-2)
     log(f"gplvm/value_and_grad n={n} d={d} q={q} m={m}: first call "
         f"{dt0:.3f} s, steady {min(times):.4f} s")
+
+
+def _drain_effects():
+    """A failed host callback (the definiteness guard) leaves its error on
+    JAX's effect tokens, which would report it again at exit; take it."""
+    import jax
+    try:
+        jax.effects_barrier()
+    except Exception:                        # noqa: BLE001 — the same error
+        pass
 
 
 def phase_reference(ck: Checks, mesh, hyp, z, rows, chunk):
@@ -555,7 +632,7 @@ def main() -> int:
                      rows=REF_ROWS, chunk=FLIGHT["chunk"])
         usps = GP_CONFIGS["gplvm-usps"]
         ck.phase("gplvm", phase_gplvm, mesh, n=usps.n, d=usps.d, q=usps.q,
-                 m=usps.m, chunk=512, steps=3)
+                 m=usps.m, chunk=512, steps=3, slice_rows=512)
 
     if ck.failed:
         log(f"FAILED: {', '.join(ck.failed)}")

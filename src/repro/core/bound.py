@@ -48,6 +48,25 @@ def _chol_kmm(hyp: dict, z: Array, jitter: float,
     return jnp.linalg.cholesky(kmm + (jitter * vs + 1e-12) * jnp.eye(m, dtype=z.dtype))
 
 
+def _raise_indefinite():
+    raise FloatingPointError(
+        "Bmat = I + beta L^-1 D L^-T is not positive definite, so the "
+        "collapsed bound is NaN.  The fused psi2 kernel sums D in float32, "
+        "which cannot resolve D when Kmm is this ill-conditioned (long "
+        "lengthscales relative to the inducing-point spacing); use "
+        "kernel_backend='xla' (float64 D) or start from shorter lengthscales.")
+
+
+def _guard_definite(LB: Array) -> None:
+    """Raise ``FloatingPointError`` on the host, from inside the compiled
+    program, when ``LB = chol(Bmat)`` failed (a failed Cholesky is NaN).
+    The check is one ``lax.cond``; its host callback runs only on failure
+    (programs that hold one are not written to the persistent cache)."""
+    ok = jnp.all(jnp.isfinite(jnp.diagonal(LB)))
+    jax.lax.cond(ok, lambda: None,
+                 lambda: jax.debug.callback(_raise_indefinite))
+
+
 def collapsed_bound(
     hyp: dict,
     z: Array,
@@ -55,8 +74,14 @@ def collapsed_bound(
     d: int,
     jitter: float = DEFAULT_JITTER,
     kernel: "cov.Kernel | None" = None,
+    guard_definite: bool = False,
 ) -> Array:
-    """Paper eq. 3.3 from reduced statistics. Returns a scalar lower bound."""
+    """Paper eq. 3.3 from reduced statistics. Returns a scalar lower bound.
+
+    ``guard_definite``: raise (``FloatingPointError``, surfaced by JAX at
+    the next sync) instead of returning NaN when ``Bmat`` is not positive
+    definite — set by the engine when ``D`` comes from the float32 psi2
+    kernel."""
     beta = jnp.exp(hyp["log_beta"])
     n = stats.n
     m = z.shape[0]
@@ -67,6 +92,8 @@ def collapsed_bound(
     W = jsl.solve_triangular(L, LiD.T, lower=True).T
     Bmat = jnp.eye(m, dtype=z.dtype) + beta * W
     LB = jnp.linalg.cholesky(Bmat)
+    if guard_definite:
+        _guard_definite(LB)
 
     # log|Bmat|
     logdet_b = 2.0 * jnp.sum(jnp.log(jnp.diagonal(LB)))

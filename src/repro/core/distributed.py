@@ -326,6 +326,14 @@ class DistributedGP:
         """Per-leaf constant-size cross-shard sum (the paper's reduce)."""
         return Stats(*(lax.psum(t, self.data_axes) for t in st))
 
+    def _bound(self, hyp, z, st: Stats, d: int):
+        """The collapsed bound from reduced Stats.  When D comes from the
+        fused float32 psi2 kernel, an indefinite ``Bmat`` raises instead
+        of returning NaN (``bound.collapsed_bound(guard_definite=True)``)."""
+        return collapsed_bound(
+            hyp, z, st, d, kernel=self.kernel,
+            guard_definite=self.latent and self.kernel_backend == "pallas")
+
     def _local_stats(self, hyp, z, y, mu, s, w, key=None, exact=False,
                      block_reduce_fn=None, reduce_buffered=True) -> Stats:
         """Shard-local map: monolithic (chunk_size=None), streamed, or —
@@ -388,7 +396,7 @@ class DistributedGP:
             )
         else:  # "drop" (paper) — keep sums as-is, n-terms use the full n
             st = st._replace(n=n_full)
-        return collapsed_bound(hyp, z, st, d, kernel=self.kernel)
+        return self._bound(hyp, z, st, d)
 
     def bound_fn(self, d: int):
         """Replicated-output distributed bound.
@@ -612,7 +620,7 @@ class DistributedGP:
                            KL=st.KL / live_frac, n=n_full)
             else:
                 st = st._replace(n=n_full)
-            return -collapsed_bound(hyp, z, st, d, kernel=self.kernel)
+            return -self._bound(hyp, z, st, d)
 
         prog = {
             "neg": jax.jit(neg),
@@ -643,7 +651,7 @@ class DistributedGP:
                            KL=st.KL / live_frac, n=n_full)
             else:
                 st = st._replace(n=n_full)
-            return collapsed_bound(hyp, z, st, d, kernel=self.kernel)
+            return self._bound(hyp, z, st, d)
 
         # NOT jitted: ``bound_fn`` hands back a bare shard_map, whose
         # op-by-op dispatch rounds like the eager path — jitting this tail
@@ -780,7 +788,7 @@ class DistributedGP:
                 st = st.scale(scale)
                 st = Stats(*(lax.psum(t, self.data_axes) for t in st))
                 st = st._replace(n=n_full)   # drop-mode n handling
-                return -collapsed_bound(hyp, z, st, d, kernel=self.kernel)
+                return -self._bound(hyp, z, st, d)
 
             stk = P(None, self.data_axes)
             rep = self._rep_spec

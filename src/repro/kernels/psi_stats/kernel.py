@@ -8,26 +8,28 @@ data shard (their §2.1/§3.2). A mechanical port would materialise the
   psi2 exponent (per point i, inducing pair (a,b), latent dim q):
     E[i,ab] = static[ab] + lognorm_i - sum_q (mu_iq - zbar_abq)^2 / den_iq
     with den_iq = ell_q^2 + 2 s_iq, zbar = (z_a + z_b)/2.
-  The kernel sums S_ab = sum_i w_i exp(E - static_ab) one inducing row a
-  at a time, straight from the definition — per latent dim q one
-  (block_n, block_m) pass of subtract, square, scale — so the live slab is
-  (block_n, block_m) and every store is a whole lane row.  ops.py hands it
-  zbar (q, m, m), the per-point columns mu and 1/den as (q, n, 1), and
-  lognorm (n, 1), all computed in the caller's f64 and rounded once, and
-  applies the i-free factor sf4 * exp(static_ab) in f64.  The exponential
-  is ``_common.exp_f32``: the USPS bound amplifies psi2 errors ~30x, and
-  the TPU's own f32 exp is off by up to 5.6e-6.  (Expanding the square
-  turns the q-loop into MXU matmuls, but its O(10) terms cancel to an O(1)
-  exponent.)
+  Expanding the square separates i from (a,b):
+    E[i,ab] - static[ab] = alpha_i + M_i . Zb_ab,
+    M = [2 mu/den, -1/den] (n, 2q),  Zb_ab = [zbar_ab, zbar_ab^2] (2q,),
+    alpha_i = lognorm_i - sum_q mu_iq^2 / den_iq.
+  The kernel sums S_ab = sum_i w_i exp(alpha_i + M_i . Zb_ab) one inducing
+  row a at a time: one (block_n, 2q) x (block_m, 2q)^T MXU matmul, the
+  exponential and a weighted sublane reduce into one lane row, so the live
+  slab is (block_n, block_m).  ops.py computes M, alpha and Zb in the
+  caller's f64 and rounds each once to f32, and applies the i-free factor
+  sf4 * exp(static_ab) in f64.  The exponential is ``_common.exp_f32``:
+  the USPS bound amplifies psi2 errors ~30x, and the TPU's own f32 exp is
+  off by up to 5.6e-6.
 
-psi1 uses that expanded form one order lower:
+psi1 uses the same expansion one order lower:
   E = alpha_i + (mu/den) z^T - (1/(2 den)) (z^2)^T, two MXU matmuls.
 
 Tiling contract (enforced/padded by ops.py):
   n % block_n == 0, m % block_m == 0, block_m % PSI2_ROWS == 0; on the TPU
   block_m is a multiple of 128 (lane-aligned output rows).  psi2 needs no
-  latent padding (q is a leading, untiled axis).  psi1 pads q NEUTRALLY:
-  padded dims carry mu=s=z=0, ell2=1, which contribute exactly 0.
+  latent padding (its 2q axis is whole in every block).  psi1 pads q
+  NEUTRALLY: padded dims carry mu=s=z=0, ell2=1, which contribute
+  exactly 0.
 """
 from __future__ import annotations
 
@@ -45,34 +47,31 @@ PSI2_ROWS = 8
 # psi2: S = sum_i w_i exp(E_iab - static_ab) — grid (a_tiles, b_tiles, n_tiles)
 # ---------------------------------------------------------------------------
 
-def _psi2_kernel(zbar_ref, mu_ref, inv_den_ref, lognorm_ref, w_ref, out_ref):
+def _psi2_kernel(zb_ref, m_ref, alpha_ref, w_ref, out_ref):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    q = mu_ref.shape[0]
-    lognorm = lognorm_ref[...]                                    # (bn, 1)
+    mm = m_ref[...]                                               # (bn, 2q)
+    alpha = alpha_ref[...]                                        # (bn, 1)
     w = w_ref[...]                                                # (bn, 1)
     for r in range(out_ref.shape[0]):
-        e = lognorm
-        for j in range(q):
-            t = mu_ref[j] - zbar_ref[j, r:r + 1, :]               # (bn, bm)
-            e = e - t * t * inv_den_ref[j]
+        e = alpha + dot_nt(mm, zb_ref[r])                         # (bn, bm)
         out_ref[r:r + 1, :] += jnp.sum(w * exp_f32(e), axis=0,
                                        keepdims=True)
 
 
-def psi2_pallas(zbar, mu, inv_den, lognorm, w, *, block_n=128, block_m=128,
+def psi2_pallas(zb, mmat, alpha, w, *, block_n=128, block_m=128,
                 interpret=False):
     """Unscaled w-weighted psi2 sums S (m, m). All inputs pre-padded and
     pre-shaped (see ops.py, which applies ``sf4 * exp(static)``).
 
-    zbar: (q, m, m); mu, inv_den: (q, n, 1); lognorm, w: (n, 1); all f32.
+    zb: (m, m, 2q); mmat: (n, 2q); alpha, w: (n, 1); all f32.
     """
-    q, n, _ = mu.shape
-    m = zbar.shape[1]
+    n, q2 = mmat.shape
+    m = zb.shape[0]
     rows = PSI2_ROWS
     assert n % block_n == 0 and m % block_m == 0 and m % rows == 0
     grid = (m // rows, m // block_m, n // block_n)
@@ -80,16 +79,15 @@ def psi2_pallas(zbar, mu, inv_den, lognorm, w, *, block_n=128, block_m=128,
         _psi2_kernel,
         grid=grid,
         in_specs=[
-            block_spec((q, rows, block_m), lambda a, b, k: (0, a, b)),  # zbar
-            block_spec((q, block_n, 1), lambda a, b, k: (0, k, 0)),     # mu
-            block_spec((q, block_n, 1), lambda a, b, k: (0, k, 0)),     # 1/den
-            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # lognorm
-            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # w
+            block_spec((rows, block_m, q2), lambda a, b, k: (a, b, 0)),  # Zb
+            block_spec((block_n, q2), lambda a, b, k: (k, 0)),           # M
+            block_spec((block_n, 1), lambda a, b, k: (k, 0)),        # alpha
+            block_spec((block_n, 1), lambda a, b, k: (k, 0)),            # w
         ],
         out_specs=block_spec((rows, block_m), lambda a, b, k: (a, b)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
         interpret=interpret,
-    )(zbar, mu, inv_den, lognorm, w)
+    )(zb, mmat, alpha, w)
 
 
 # ---------------------------------------------------------------------------

@@ -37,16 +37,18 @@ def _psi2_fwd_impl(block_n, block_m, interpret, hyp, z, mu, s, w):
     f32 = jnp.float32
     ell2 = jnp.exp(2.0 * hyp["log_ell"])                            # (q,)
     den = ell2 + 2.0 * s                                             # (n, q)
+    inv_den = 1.0 / den
     lognorm = -0.5 * jnp.sum(jnp.log(den / ell2), axis=1, keepdims=True)
-
-    def cols(a):                               # (n, q) -> (q, n_pad, 1) f32
-        return _pad_to(a.T[:, :, None].astype(f32), block_n, 1)
+    alpha = lognorm - jnp.sum(mu * mu * inv_den, axis=1, keepdims=True)
+    mmat = jnp.concatenate([2.0 * mu * inv_den, -inv_den], axis=1)  # (n, 2q)
 
     # Padded rows are all zeros (exponent 0) and carry w = 0.
     z_p = _pad_to(z, block_m, 0)
-    zbar = 0.5 * (z_p.T[:, :, None] + z_p.T[:, None, :])   # (q, m_pad, m_pad)
-    out = _k.psi2_pallas(zbar.astype(f32), cols(mu), cols(1.0 / den),
-                         _pad_to(lognorm.astype(f32), block_n, 0),
+    zbar = 0.5 * (z_p[:, None, :] + z_p[None, :, :])        # (m_pad, m_pad, q)
+    zb = jnp.concatenate([zbar, zbar * zbar], axis=-1)      # (m_pad, m_pad, 2q)
+    out = _k.psi2_pallas(zb.astype(f32),
+                         _pad_to(mmat.astype(f32), block_n, 0),
+                         _pad_to(alpha.astype(f32), block_n, 0),
                          _pad_to(w.astype(f32)[:, None], block_n, 0),
                          block_n=block_n, block_m=block_m,
                          interpret=interpret)

@@ -51,7 +51,12 @@ def _fwd_impl(block_n, block_m, interpret, hyp, z, x, y, w):
     b, c, d_stat = _k.reg_stats_pallas(inv_p, sf2, z_p, x_p, y_p, w_p,
                                        block_n=block_n, block_m=block_m,
                                        interpret=interpret)
-    return b[0, 0], c[:m, :d], d_stat[:m, :m]
+    # Back to the caller's dtype: the chunked map folds blocks in the
+    # dtype a block returns, and an f32 fold over ~1000 blocks costs ~1e-6
+    # relative (and makes the sum depend on how rows are split over shards).
+    out = x.dtype
+    return (b[0, 0].astype(out), c[:m, :d].astype(out),
+            d_stat[:m, :m].astype(out))
 
 
 def _vjp_fwd(block_n, block_m, interpret, hyp, z, x, y, w):

@@ -76,6 +76,45 @@ def test_psi2_kernel_matches_core_engine_stats(rng):
                                rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("log_ell,log_beta,conditioned", [
+    (np.log(0.5), 0.0, True),
+    (np.log(2.0), 0.0, False),   # Kmm too ill-conditioned for f32 D
+])
+def test_fused_psi2_bound_raises_when_bmat_is_indefinite(
+        log_ell, log_beta, conditioned):
+    """The engine's fused (float32 D) GPLVM bound matches the float64 XLA
+    bound where Kmm is well conditioned, and raises — instead of returning
+    NaN — where float32 D makes Bmat indefinite."""
+    import jax
+
+    from repro.core import DistributedGP
+    from repro.launch.mesh import make_compat_mesh
+
+    rng = np.random.default_rng(0)
+    n, q, m, d = 128, 2, 24, 3
+    mu, y = rng.standard_normal((n, q)), rng.standard_normal((n, d))
+    z = jnp.asarray(rng.standard_normal((m, q)))
+    hyp = {"log_sf2": jnp.asarray(0.0), "log_ell": jnp.full((q,), log_ell),
+           "log_beta": jnp.asarray(log_beta)}
+    mesh = make_compat_mesh((1,), ("data",))
+
+    def bound(backend):
+        eng = DistributedGP(mesh, latent=True, chunk_size=64,
+                            kernel_backend=backend)
+        data, w = eng.put_data(y=y, mu=mu, s=np.full((n, q), 0.5))
+        return float(jax.jit(eng.bound_fn(d))(
+            hyp, z, data["y"], data["mu"], data["s"], w, jnp.ones((1,)),
+            jnp.asarray(float(n))))
+
+    want = bound("xla")
+    assert np.isfinite(want)
+    if conditioned:
+        np.testing.assert_allclose(bound("pallas"), want, rtol=1e-5)
+    else:
+        with pytest.raises(Exception, match="not positive definite"):
+            bound("pallas")
+
+
 @pytest.mark.parametrize("b,h,hkv,t,s,dh,causal,dtype", [
     (2, 4, 2, 64, 64, 64, True, jnp.float32),
     (1, 8, 1, 70, 70, 64, True, jnp.float32),      # MQA, ragged t

@@ -76,6 +76,24 @@ def test_reg_stats_f32_path(rng):
                                rtol=2e-4, atol=2e-5)
 
 
+def test_tpu_branch_folds_blocks_in_caller_dtype(rng):
+    """On the TPU the kernel computes in f32 but hands back the caller's
+    f64, so the chunked map folds its blocks in f64: an f32 carry loses
+    ~1e-6 over 1000 blocks and depends on how rows are split over shards.
+    Abstract evaluation only — the TPU branch is not lowered here."""
+    n, m, q, d = 96, 24, 3, 2
+    hyp = _hyp(rng, q)
+    z, x, y, w = _mk(rng, n, m, q, d)
+
+    def fused(hyp, z, x, y, w):
+        return rs_ops.reg_stats(hyp, z, x, y, w, interpret=False)
+
+    st = jax.eval_shape(lambda: partial_stats_chunked(
+        hyp, z, y, x, s=None, weights=w, latent=False, reg_stats_fn=fused,
+        block_size=32))
+    assert all(t.dtype == jnp.float64 for t in st), st
+
+
 def test_partial_stats_hook_parity(rng):
     """reg_stats_fn plugs into partial_stats and reproduces every statistic,
     including with masked (zero-weight) rows."""
