@@ -1,0 +1,6 @@
+"""The whole step's share of the chips' peak rate, for exact iterations."""
+from bench.metrics import _mfu
+
+
+def read(ctx):
+    return _mfu.share(ctx)
