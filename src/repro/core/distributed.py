@@ -57,6 +57,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -807,20 +808,29 @@ class DistributedGP:
             n_full = float(stream.n) if n_full is None else n_full
             nc = stream.n_chunks
             B = min(batch_chunks, nc)
-            if B < nc:
-                idxs = np.asarray(sample_block_indices(key, nc, B))
-            else:
-                idxs = np.arange(nc)
+            # Host spans, for a profiler trace to put the device's idle
+            # time down to: sampling, assembly (``BlockStream.chunk``),
+            # host-to-device staging, dispatch.
+            with TraceAnnotation("svi_sample"):
+                if B < nc:
+                    idxs = np.asarray(sample_block_indices(key, nc, B))
+                else:
+                    idxs = np.arange(nc)
             chunks = [stream.chunk(int(c)) for c in idxs]
-            arrs = {k: jax.device_put(
-                        jnp.asarray(np.stack([c[0][k] for c in chunks])),
-                        stacked_sharding)
-                    for k in stream.fields}
-            w = jax.device_put(jnp.asarray(np.stack([c[1] for c in chunks])),
-                               stacked_sharding)
-            scale = jnp.asarray(nc / B, jnp.float64)
-            return prog(hyp, z, arrs["y"], arrs["mu"], arrs.get("s"), w,
-                        fmask, n_full, scale)
+            with TraceAnnotation("svi_h2d") as span:
+                arrs = {k: jax.device_put(
+                            jnp.asarray(np.stack([c[0][k] for c in chunks])),
+                            stacked_sharding)
+                        for k in stream.fields}
+                w = jax.device_put(
+                    jnp.asarray(np.stack([c[1] for c in chunks])),
+                    stacked_sharding)
+                span.set_metadata(bytes=w.nbytes + sum(
+                    a.nbytes for a in arrs.values()))
+            with TraceAnnotation("svi_dispatch"):
+                scale = jnp.asarray(nc / B, jnp.float64)
+                return prog(hyp, z, arrs["y"], arrs["mu"], arrs.get("s"), w,
+                            fmask, n_full, scale)
 
         return step
 

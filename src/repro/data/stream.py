@@ -40,9 +40,11 @@ import pathlib
 import queue
 import threading
 import zipfile
+from functools import partial
 from typing import Callable, Iterable, Iterator
 
 import numpy as np
+from jax.profiler import annotate_function
 
 __all__ = [
     "ArraySource", "MemmapSource", "SyntheticSource", "as_source",
@@ -263,6 +265,8 @@ class BlockStream:
         win = self.source.read(0, 0 if self.n == 0 else 1)
         return np.asarray(win[k]).dtype
 
+    # A host span for a profiler trace: one per chunk assembled.
+    @partial(annotate_function, name="chunk_assemble")
     def chunk(self, c: int):
         """Assemble chunk ``c`` -> ``(dict of (chunk_rows, ...) arrays,
         weights (chunk_rows,))``; weights are 1.0 exactly on real rows."""
