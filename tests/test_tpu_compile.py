@@ -78,6 +78,22 @@ def test_reg_stats_compiles(one_chip, width):
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_reg_stats_bwd_compiles(one_chip, width):
+    """The backward kernel at the widths (m=150 takes two inducing tiles)."""
+    q, d, m = WIDTHS[width]
+
+    def f(hyp, z, x, y, w, cts):
+        return reg_ops.reg_stats_bwd(hyp, z, x, y, w, cts, block_n=128,
+                                     block_m=128, interpret=False)
+
+    _assert_fused(jax.jit(f).lower(
+        _hyp(q, one_chip), _sds((m, q), one_chip), _sds((ROWS, q), one_chip),
+        _sds((ROWS, d), one_chip), _sds((ROWS,), one_chip),
+        (_sds((), one_chip), _sds((m, d), one_chip),
+         _sds((m, m), one_chip))).compile())
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_psi2_compiles(one_chip, width):
     q, _, m = WIDTHS[width]
 
