@@ -51,10 +51,10 @@ def _chol_kmm(hyp: dict, z: Array, jitter: float,
 def _raise_indefinite():
     raise FloatingPointError(
         "Bmat = I + beta L^-1 D L^-T is not positive definite, so the "
-        "collapsed bound is NaN.  The fused psi2 kernel sums D in float32, "
-        "which cannot resolve D when Kmm is this ill-conditioned (long "
-        "lengthscales relative to the inducing-point spacing); use "
-        "kernel_backend='xla' (float64 D) or start from shorter lengthscales.")
+        "collapsed bound is NaN: D is not accurate enough to resolve Kmm "
+        "at this conditioning (long lengthscales relative to the "
+        "inducing-point spacing; a float32 D fails first).  Compute D in "
+        "float64 or start from shorter lengthscales.")
 
 
 def _guard_definite(LB: Array) -> None:

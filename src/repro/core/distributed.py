@@ -329,8 +329,8 @@ class DistributedGP:
 
     def _bound(self, hyp, z, st: Stats, d: int):
         """The collapsed bound from reduced Stats.  When D comes from the
-        fused float32 psi2 kernel, an indefinite ``Bmat`` raises instead
-        of returning NaN (``bound.collapsed_bound(guard_definite=True)``)."""
+        fused psi2 kernel, an indefinite ``Bmat`` raises instead of
+        returning NaN (``bound.collapsed_bound(guard_definite=True)``)."""
         return collapsed_bound(
             hyp, z, st, d, kernel=self.kernel,
             guard_definite=self.latent and self.kernel_backend == "pallas")

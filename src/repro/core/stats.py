@@ -109,14 +109,17 @@ def partial_stats(
             b, c, d_stat = reg_stats_fn(hyp, z, mu, y, w)
         kl = jnp.zeros((), y.dtype)
     else:
+        # Named scopes split the latent map (and its backward) in a trace.
         a = jnp.sum(w * jnp.sum(y * y, axis=-1))
-        b = jnp.sum(w * kernel.psi0(hyp, mu, s))
-        p1 = kernel.psi1(hyp, z, mu, s)                        # (n, m)
-        c = p1.T @ (w[:, None] * y)
-        if psi2_fn is None:
-            d_stat = kernel.psi2(hyp, z, mu, s, w)
-        else:
-            d_stat = psi2_fn(hyp, z, mu, s, w)
+        with jax.named_scope("psi1_c"):
+            b = jnp.sum(w * kernel.psi0(hyp, mu, s))
+            p1 = kernel.psi1(hyp, z, mu, s)                    # (n, m)
+            c = p1.T @ (w[:, None] * y)
+        with jax.named_scope("psi2"):
+            if psi2_fn is None:
+                d_stat = kernel.psi2(hyp, z, mu, s, w)
+            else:
+                d_stat = psi2_fn(hyp, z, mu, s, w)
         kl_i = 0.5 * jnp.sum(s + mu * mu - jnp.log(s) - 1.0, axis=-1)
         kl = jnp.sum(w * kl_i) if latent else jnp.zeros((), y.dtype)
 

@@ -40,29 +40,6 @@ def block_spec(block_shape, index_map):
 
 _HI = lax.Precision.HIGHEST
 
-_LOG2E = 1.4426950408889634
-_LN2_HI, _LN2_LO = 0.693359375, -2.12194440e-4     # Cody-Waite split of ln 2
-
-
-def exp_f32(x):
-    """``exp`` of an f32 array to f32 round-off, from VPU multiply-adds.
-
-    The TPU's own f32 ``exp`` (Mosaic and XLA alike) is off by up to
-    5.6e-6 relative on a v5e (1.3e-6 on average), and the USPS GPLVM
-    bound amplifies psi2 errors ~30x; this one stays under 1e-7.
-    ``exp(x) = 2^k exp(r)``, ``|r| <= ln2 / 2``, ``exp(r)`` by its Taylor
-    series to degree 7, ``2^k`` built in the exponent bits; results below
-    the f32 normal range are 0 (the TPU flushes them anyway)."""
-    k = jnp.floor(x * _LOG2E + 0.5)
-    r = (x - k * _LN2_HI) - k * _LN2_LO
-    p = 1.0 / 5040.0
-    for c in (1.0 / 720, 1.0 / 120, 1.0 / 24, 1.0 / 6, 0.5, 1.0, 1.0):
-        p = p * r + c
-    ki = jnp.maximum(k, -126.0).astype(jnp.int32)
-    two_k = lax.bitcast_convert_type(
-        lax.shift_left(ki + 127, jnp.int32(23)), jnp.float32)
-    return jnp.where(x < -87.0, 0.0, p * two_k)
-
 
 def dot_nt(a, b):
     """``a @ b.T`` contracting both last (lane) dims — the MXU's native

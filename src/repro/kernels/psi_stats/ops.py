@@ -1,16 +1,17 @@
 """jit'd public wrappers around the psi-statistics Pallas kernels.
 
 Handles padding to tile boundaries (all pads are NEUTRAL — padded data
-rows carry w=0; padded inducing rows are sliced off the output; psi1's
-padded latent dims carry mu=s=z=0, ell2=1), the operand layouts the
-kernels want, backend selection (interpret=True off-TPU), and the
-hyper-parameter plumbing from the core library's log-space dict.
+rows carry w=0 (psi2: c=0); padded inducing rows and psi2's padded pairs
+are sliced off the output; psi1's padded latent dims carry mu=s=z=0,
+ell2=1), the operand layouts the kernels want (psi2's limbs and hi/lo
+pairs, computed in f64), backend selection (interpret=True off-TPU), and
+the hyper-parameter plumbing from the core library's log-space dict.
 
 ``psi2`` carries a ``custom_vjp`` (``pallas_call`` has no VJP on this JAX
 version): forward is the Pallas kernel, backward recomputes through the
-MXU-matmul XLA reformulation (``gp_kernels.psi2_mxu``) — so the kernel can
-sit inside ``jax.grad`` of the bound (the engine's ``kernel_backend=
-"pallas"`` path).
+MXU-matmul XLA reformulation (``gp_kernels.psi2_mxu``, over the pairs
+a <= b) in f64 — so the kernel can sit inside ``jax.grad`` of the
+bound (the engine's ``kernel_backend="pallas"`` path).
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core import gp_kernels as gpk
 from .._common import on_tpu as _on_tpu
@@ -26,63 +28,86 @@ from . import kernel as _k
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _psi2(block_n, block_m, interpret, hyp, z, mu, s, w):
-    return _psi2_fwd_impl(block_n, block_m, interpret, hyp, z, mu, s, w)
+def _psi2(block_n, block_p, interpret, hyp, z, mu, s, w):
+    return _psi2_fwd_impl(block_n, block_p, interpret, hyp, z, mu, s, w)
 
 
-def _psi2_fwd_impl(block_n, block_m, interpret, hyp, z, mu, s, w):
-    # Everything outside the kernel runs in the caller's dtype (f64 here):
-    # the TPU's f32 log/exp would cost more accuracy than the kernel does.
+def _limbs(v, grid1, grid2):
+    """``v = v1 + v2 + v3`` in f64: v1 on ``grid1``, v2 on ``grid2`` with
+    ``|v2| <= grid1 / 2``, and the rest; exact."""
+    v1 = jnp.round(v / grid1) * grid1
+    r = v - v1
+    v2 = jnp.round(r / grid2) * grid2
+    return v1, v2, r - v2
+
+
+def _psi2_fwd_impl(block_n, block_p, interpret, hyp, z, mu, s, w):
+    # Everything outside the kernel runs in f64: the kernel's operands are
+    # f64 values cut into f32 limbs or hi/lo pairs (kernel.py).
+    f32, f64 = jnp.float32, jnp.float64
+    out_dtype = jnp.result_type(z, mu)
+    z, mu, s, w = (jnp.asarray(a, f64) for a in (z, mu, s, w))
     m = z.shape[0]
-    f32 = jnp.float32
-    ell2 = jnp.exp(2.0 * hyp["log_ell"])                            # (q,)
+    ell2 = jnp.exp(2.0 * jnp.asarray(hyp["log_ell"], f64))          # (q,)
     den = ell2 + 2.0 * s                                             # (n, q)
-    inv_den = 1.0 / den
-    lognorm = -0.5 * jnp.sum(jnp.log(den / ell2), axis=1, keepdims=True)
-    alpha = lognorm - jnp.sum(mu * mu * inv_den, axis=1, keepdims=True)
-    mmat = jnp.concatenate([2.0 * mu * inv_den, -inv_den], axis=1)  # (n, 2q)
+    inv = 1.0 / den
+    c = w * jnp.exp(-0.5 * jnp.sum(jnp.log(den / ell2), axis=1))     # (n,)
+    a, b = np.triu_indices(m)
+    zbar = 0.5 * (z[a] + z[b])                                       # (P, q)
 
-    # Padded rows are all zeros (exponent 0) and carry w = 0.
-    z_p = _pad_to(z, block_m, 0)
-    zbar = 0.5 * (z_p[:, None, :] + z_p[None, :, :])        # (m_pad, m_pad, q)
-    zb = jnp.concatenate([zbar, zbar * zbar], axis=-1)      # (m_pad, m_pad, 2q)
-    out = _k.psi2_pallas(zb.astype(f32),
-                         _pad_to(mmat.astype(f32), block_n, 0),
-                         _pad_to(alpha.astype(f32), block_n, 0),
-                         _pad_to(w.astype(f32)[:, None], block_n, 0),
-                         block_n=block_n, block_m=block_m,
-                         interpret=interpret)
+    # One grid for mu and zbar, so their limbs' differences are exact:
+    # |v| <= 2^e, v1 on 2^(e-11), v2 on 2^(e-22) (12 bits each; their
+    # differences' sum has at most 24).
+    top = jnp.maximum(jnp.max(jnp.abs(mu)), jnp.max(jnp.abs(zbar)))
+    e = jnp.floor(jnp.log2(jnp.maximum(top, 1e-30))) + 1.0
+    grid1, grid2 = jnp.exp2(e - 11.0), jnp.exp2(e - 22.0)
+    mu_l, zb_l = _limbs(mu, grid1, grid2), _limbs(zbar, grid1, grid2)
+
+    def pair(v):
+        hi = v.astype(f32)
+        return hi, (v - hi).astype(f32)
+
+    cols = [*mu_l, *pair(inv), *(t[:, None] for t in pair(c))]
+    rows = jnp.concatenate([t.astype(f32) for t in cols], axis=1)
+    zp = jnp.concatenate([t.astype(f32) for t in zb_l], axis=1).T  # (3q, P)
+    hi, lo = _k.psi2_pallas(_pad_to(zp, block_p, 1),
+                            _pad_to(rows, block_n, 0),
+                            block_n=block_n, block_p=block_p,
+                            interpret=interpret)
+    sums = (jnp.sum(hi.astype(f64), axis=0)
+            + jnp.sum(lo.astype(f64), axis=0))[:a.size]
+    d_stat = gpk.from_pairs(sums, m)
     # The i-free factor of every term: sf4 * exp(-1/4 sum_q dz^2 / ell2).
     dz = z[:, None, :] - z[None, :, :]
     static = -0.25 * jnp.sum(dz * dz / ell2, axis=-1)               # (m, m)
-    sf4 = jnp.exp(2.0 * hyp["log_sf2"])
-    return sf4 * jnp.exp(static) * out[:m, :m].astype(static.dtype)
+    sf4 = jnp.exp(2.0 * jnp.asarray(hyp["log_sf2"], f64))
+    return (sf4 * jnp.exp(static) * d_stat).astype(out_dtype)
 
 
-def _psi2_vjp_fwd(block_n, block_m, interpret, hyp, z, mu, s, w):
-    out = _psi2_fwd_impl(block_n, block_m, interpret, hyp, z, mu, s, w)
+def _psi2_vjp_fwd(block_n, block_p, interpret, hyp, z, mu, s, w):
+    out = _psi2_fwd_impl(block_n, block_p, interpret, hyp, z, mu, s, w)
     return out, (hyp, z, mu, s, w)
 
 
-def _psi2_vjp_bwd(block_n, block_m, interpret, res, ct):
-    del block_n, block_m, interpret
-    # Backward recompute via the XLA MXU reformulation; chunk=256 bounds the
-    # live (chunk, m^2) intermediate under the streaming engine's blocks.
-    out, vjp = jax.vjp(
-        lambda h, zz, mm, ss, ww: gpk.psi2_mxu(h, zz, mm, ss, ww, chunk=256),
-        *res)
-    return vjp(jnp.asarray(ct, out.dtype))
+def _psi2_vjp_bwd(block_n, block_p, interpret, res, ct):
+    del block_n, block_p, interpret
+    # Backward recompute via the XLA MXU reformulation, f64, over the pairs
+    # a <= b; chunk=256 bounds the live (chunk, P) intermediate.
+    out, vjp = jax.vjp(functools.partial(gpk.psi2_mxu, chunk=256), *res)
+    return vjp(jnp.asarray(ct).astype(out.dtype))
 
 
 _psi2.defvjp(_psi2_vjp_fwd, _psi2_vjp_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
-def psi2(hyp: dict, z, mu, s, w, block_n: int = 128, block_m: int = 128,
+@functools.partial(jax.jit, static_argnames=("block_n", "block_p", "interpret"))
+def psi2(hyp: dict, z, mu, s, w, block_n: int = 256, block_p: int = 256,
          interpret: bool | None = None):
-    """Weighted Psi2 = sum_i w_i <K_mi K_im> via the Pallas kernel. (m, m)."""
+    """Weighted Psi2 = sum_i w_i <K_mi K_im> via the Pallas kernel, (m, m),
+    to about 1e-13 relative per entry.  ``block_n`` rows and ``block_p``
+    inducing pairs (a <= b) per grid step."""
     interpret = (not _on_tpu()) if interpret is None else interpret
-    return _psi2(block_n, block_m, interpret, hyp, z, mu, s, w)
+    return _psi2(block_n, block_p, interpret, hyp, z, mu, s, w)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
@@ -106,7 +131,7 @@ def psi1(hyp: dict, z, mu, s, block_n: int = 256, block_m: int = 128,
     return out[:n, :m]
 
 
-def psi2_fn_for_engine(block_n: int = 128, block_m: int = 128, kernel=None):
+def psi2_fn_for_engine(block_n: int = 256, block_p: int = 256, kernel=None):
     """Adapter matching core.stats.partial_stats(psi2_fn=...) signature.
 
     Dispatch shim for the compositional kernel layer: the fused Pallas
@@ -120,7 +145,7 @@ def psi2_fn_for_engine(block_n: int = 128, block_m: int = 128, kernel=None):
     kernel = as_kernel(kernel)
     if is_fused_se(kernel):
         def fn(hyp, z, mu, s, w):
-            return psi2(hyp, z, mu, s, w, block_n=block_n, block_m=block_m)
+            return psi2(hyp, z, mu, s, w, block_n=block_n, block_p=block_p)
     else:
         def fn(hyp, z, mu, s, w):
             return kernel.psi2(hyp, z, mu, s, w)

@@ -127,9 +127,6 @@ def lower_gp_cell(name: str, mesh, variant: str = "mxu"):
     if variant == "mxu":      # beyond-paper MXU-matmul reformulation
         def psi2_fn(hyp, z, mu, s, w):
             return gpk.psi2_mxu(hyp, z, mu, s, w, chunk=512)
-    elif variant == "sym":    # + exploit Psi2 symmetry (~2x less pair work)
-        def psi2_fn(hyp, z, mu, s, w):
-            return gpk.psi2_mxu_sym(hyp, z, mu, s, w, chunk=512, tile=64)
 
     t0 = time.time()
     eng = DistributedGP(mesh, data_axes=axes, latent=gp.latent,

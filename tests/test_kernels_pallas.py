@@ -27,24 +27,10 @@ def test_psi2_kernel_shapes(rng, n, m, q):
     mu = jnp.asarray(rng.standard_normal((n, q)))
     s = jnp.asarray(rng.uniform(0.05, 0.8, (n, q)))
     w = jnp.asarray((rng.uniform(size=n) > 0.1).astype(np.float64))
-    out = ps_ops.psi2(hyp, z, mu, s, w, block_n=64, block_m=32)
+    out = ps_ops.psi2(hyp, z, mu, s, w, block_n=64, block_p=32)
     want = ps_ref.psi2_ref(hyp["log_sf2"], hyp["log_ell"], z, mu, s, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
-
-
-def test_exp_f32_matches_f64_exp():
-    """The kernels' VPU exponential: f32 round-off over the whole f32
-    range, exactly 0 below it."""
-    from repro.kernels._common import exp_f32
-
-    x = np.concatenate([np.linspace(-87.0, 20.0, 200_001),
-                        [-1e-30, 0.0, 1e-30]]).astype(np.float32)
-    got = np.asarray(exp_f32(jnp.asarray(x)), np.float64)
-    want = np.exp(x.astype(np.float64))
-    assert np.max(np.abs(got - want) / want) < 2e-7
-    assert np.all(np.asarray(exp_f32(jnp.asarray([-88.0, -1e4],
-                                                 jnp.float32))) == 0.0)
 
 
 @pytest.mark.parametrize("n,m,q", [(64, 16, 2), (100, 37, 3), (130, 129, 5)])
@@ -82,9 +68,10 @@ def test_psi2_kernel_matches_core_engine_stats(rng):
 ])
 def test_fused_psi2_bound_raises_when_bmat_is_indefinite(
         log_ell, log_beta, conditioned):
-    """The engine's fused (float32 D) GPLVM bound matches the float64 XLA
-    bound where Kmm is well conditioned, and raises — instead of returning
-    NaN — where float32 D makes Bmat indefinite."""
+    """The engine's fused GPLVM bound matches the float64 XLA bound, also
+    where Kmm is too ill-conditioned for a float32 D; and where a float32
+    D (the fused kernel's D rounded) makes Bmat indefinite, the bound
+    raises instead of returning NaN."""
     import jax
 
     from repro.core import DistributedGP
@@ -98,9 +85,12 @@ def test_fused_psi2_bound_raises_when_bmat_is_indefinite(
            "log_beta": jnp.asarray(log_beta)}
     mesh = make_compat_mesh((1,), ("data",))
 
-    def bound(backend):
+    def rounded(*args):
+        return ps_ops.psi2(*args).astype(jnp.float32).astype(jnp.float64)
+
+    def bound(backend, psi2_fn=None):
         eng = DistributedGP(mesh, latent=True, chunk_size=64,
-                            kernel_backend=backend)
+                            kernel_backend=backend, psi2_fn=psi2_fn)
         data, w = eng.put_data(y=y, mu=mu, s=np.full((n, q), 0.5))
         return float(jax.jit(eng.bound_fn(d))(
             hyp, z, data["y"], data["mu"], data["s"], w, jnp.ones((1,)),
@@ -108,11 +98,12 @@ def test_fused_psi2_bound_raises_when_bmat_is_indefinite(
 
     want = bound("xla")
     assert np.isfinite(want)
+    np.testing.assert_allclose(bound("pallas"), want, rtol=1e-10)
     if conditioned:
-        np.testing.assert_allclose(bound("pallas"), want, rtol=1e-5)
+        np.testing.assert_allclose(bound("pallas", rounded), want, rtol=1e-5)
     else:
         with pytest.raises(Exception, match="not positive definite"):
-            bound("pallas")
+            bound("pallas", rounded)
 
 
 @pytest.mark.parametrize("b,h,hkv,t,s,dh,causal,dtype", [
