@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import repro  # noqa: F401  (float64 on)
+from repro.core import gp_kernels as gpk
 from repro.kernels.psi_stats import kernel as psi_kernel
 from repro.kernels.psi_stats import ops as psi_ops
 from repro.kernels.psi_stats import ref as psi_ref
@@ -117,6 +118,82 @@ def test_psi2_backward_over_the_pairs_matches_the_closed_form():
         return psi_ref.psi2_ref(h["log_sf2"], h["log_ell"], *rest)
 
     got = jax.vjp(lambda *a: psi_ops.psi2(*a, interpret=True), *args)[1](ct)
+    want = jax.vjp(ref, *args)[1](ct)
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-10, atol=1e-12)
+
+
+def _worst_leaf(got, want):
+    """The largest leaf error relative to the leaf's norm, over the leaves
+    with a cotangent."""
+    return max(np.linalg.norm(np.asarray(g) - np.asarray(r))
+               / np.linalg.norm(np.asarray(r))
+               for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want))
+               if np.linalg.norm(np.asarray(r)) > 0.0)
+
+
+def test_psi2_backward_kernel_holds_float64_accuracy_on_a_whitened_cotangent(
+        ill_conditioned):
+    """The backward kernel (``ops.psi2_bwd``) against autodiff of the f64
+    ``gp_kernels.psi2_mxu`` where Kmm is ill-conditioned, with the shape of
+    cotangent the bound feeds back, ``L^-T X L^-1`` (X random, symmetric):
+    every leaf within 1e-8 of its norm, the bar the forward's whitened
+    test holds psi2 to.  It reads 1.2e-9 (mu; log_sf2 2.3e-11).  The same
+    kernel with its sums in plain f32 (each ``_add`` one rounded add)
+    reads 2.7e-3 (log_sf2), so the limit tells the two apart."""
+    args, _, l_inv = ill_conditioned
+    x = np.random.default_rng(7).standard_normal(l_inv.shape)
+    ct = jnp.asarray(l_inv.T @ (x + x.T) @ l_inv)
+    want = jax.vjp(gpk.psi2_mxu, *args)[1](ct)
+    err = _worst_leaf(psi_ops.psi2_bwd(*args, ct, block_n=256, block_p=256,
+                                       interpret=True), want)
+    assert err < 1e-8, err
+
+    def plain_add(a, b):
+        s = a[0] + b[0]
+        return s, jnp.zeros_like(s)
+
+    compensated = psi_kernel._add
+    psi_kernel._add = plain_add
+    try:        # unjitted, so the kernel is traced again with plain_add
+        plain = _worst_leaf(psi_ops.psi2_bwd.__wrapped__(
+            *args, ct, block_n=256, block_p=256, interpret=True), want)
+    finally:
+        psi_kernel._add = compensated
+    assert plain >= 100 * err, (plain, err)
+
+
+@pytest.mark.parametrize("block_n, block_p, m, q", [
+    (8, 128, 13, 3), (16, 128, 20, 3), (72, 256, 20, 3), (16, 32, 20, 3),
+    (8, 128, 13, 33)],
+    ids=["8-128-13", "16-128-20", "72-256-20", "16-32-20", "8-128-13-q33"])
+def test_psi2_backward_kernel_tiles_and_padding(block_n, block_p, m, q):
+    """Every cotangent of ``ops.psi2`` (the backward kernel at the op's
+    tiles) against autodiff of the f64 closed form, over row and pair
+    tiles: 70 rows, a fifth with w = 0, padded to ``block_n`` with more;
+    m(m + 1)/2 = 91 or 210 pairs, padded to ``block_p`` (rounded up to
+    128 lanes); q = 33 puts the 2q + 1 row sums in two lane blocks
+    (1e-10, as the pairs test above)."""
+    rng = np.random.default_rng(11)
+    n = 70
+    hyp = {"log_sf2": jnp.asarray(-0.2), "log_ell": jnp.asarray(
+        rng.uniform(-0.4, 0.4, q) + 0.5 * np.log(q / 3)),
+        "log_beta": jnp.asarray(0.0)}
+    args = (hyp, jnp.asarray(rng.standard_normal((m, q))),
+            jnp.asarray(rng.standard_normal((n, q))),
+            jnp.asarray(rng.uniform(0.05, 0.8, (n, q))),
+            jnp.asarray((rng.uniform(size=n) > 0.2).astype(np.float64)))
+    ct = jnp.asarray(rng.standard_normal((m, m)))
+
+    def ref(h, *rest):
+        return psi_ref.psi2_ref(h["log_sf2"], h["log_ell"], *rest)
+
+    def kernel(*a):
+        return psi_ops.psi2(*a, block_n=block_n, block_p=block_p,
+                            interpret=True)
+
+    got = jax.vjp(kernel, *args)[1](ct)
     want = jax.vjp(ref, *args)[1](ct)
     for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),

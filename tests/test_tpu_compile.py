@@ -105,6 +105,32 @@ def test_psi2_compiles(one_chip, width):
         _sds((ROWS, q), one_chip), _sds((ROWS,), one_chip)).compile())
 
 
+# (q, m, rows a block): the USPS GPLVM's streaming block, the MNIST cell's.
+PSI2_BWD = {"usps": (10, 150, ROWS), "mnist": (10, 150, 512)}
+
+
+@pytest.mark.parametrize("width", sorted(PSI2_BWD))
+def test_psi2_bwd_compiles(one_chip, width):
+    """psi2's backward kernel at the GPLVMs' block shapes and the engine's
+    tiles, as one instruction named ``psi2_bwd`` (what ``psi2_bwd_roofline.gplvm``
+    reads in a profile)."""
+    q, m, n = PSI2_BWD[width]
+
+    def f(hyp, z, mu, s, w, ct):
+        return psi_ops.psi2_bwd(hyp, z, mu, s, w, ct, block_n=256,
+                                block_p=256, interpret=False)
+
+    text = jax.jit(f).lower(
+        _hyp(q, one_chip), _sds((m, q), one_chip), _sds((n, q), one_chip),
+        _sds((n, q), one_chip), _sds((n,), one_chip),
+        _sds((m, m), one_chip)).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1
+    assert kernels[0].lstrip().startswith(("%psi2_bwd.", "psi2_bwd.")), \
+        kernels[0][:80]
+
+
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_predict_compiles(one_chip, width):
     q, d, m = WIDTHS[width]
